@@ -1,9 +1,1 @@
-"""repro — KaMPIng-style named-parameter collectives for JAX SPMD.
-
-Importing the package installs the jax forward-compat backfill (see
-:mod:`repro.compat`) so the modern API surface the repo is written
-against works on older jax runtimes too.
-"""
-from . import compat as _compat
-
-_compat.install()
+"""repro — KaMPIng-style named-parameter collectives for JAX SPMD."""

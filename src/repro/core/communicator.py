@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import axis_size as _axis_size
 from . import groups as _groups
 from .compression import get_codec
 from .errors import KampingError
@@ -158,7 +157,7 @@ class Communicator:
         communicator's parent world (cf. MPI_COMM_WORLD's size)."""
         n = 1
         for a in self._axes:
-            n *= _axis_size(a)
+            n *= lax.axis_size(a)
         return n
 
     def size(self) -> int:
@@ -449,7 +448,6 @@ class Communicator:
             isinstance(r, (int, np.integer))
             and len(self._axes) == 1
             and self.groups is None
-            and hasattr(lax, "pbroadcast")
             and jax.default_backend() == "tpu"
         ):
             # Static root -> the hardware-optimized CollectiveBroadcast HLO.

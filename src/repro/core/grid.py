@@ -32,7 +32,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size as _axis_size
 from .errors import KampingError
 from .opspec import OP_TABLE, attach_ops
 from .plugins import Plugin
@@ -60,7 +59,7 @@ class GridCommunicator(Plugin):
         identical to the flat all_to_all, with 2·(√p) messages.
         """
         rows_ax, cols_ax = self._grid_axes()
-        sr, sc = _axis_size(rows_ax), _axis_size(cols_ax)
+        sr, sc = lax.axis_size(rows_ax), lax.axis_size(cols_ax)
         p = sr * sc
         if x.shape[0] != p:
             raise KampingError(

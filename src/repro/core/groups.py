@@ -26,7 +26,10 @@ unchanged.  This module is the JAX realization (DESIGN.md §9):
 
 Lowering strategy: each grouped primitive first attempts the native
 ``axis_index_groups`` lowering (the hardware path under ``shard_map`` /
-``pmap``); where the running JAX lacks a rule — notably the vmap-as-SPMD
+``pmap``) on the payload flattened behind its group dimension — the TPU
+compiler takes seconds per MiB to compile a grouped collective over a
+deeper operand, and a fraction of a second over a flat one; where the
+running JAX lacks a rule — notably the vmap-as-SPMD
 test interpreter, and grouped ``psum`` under some shard_map versions —
 it falls back to an *emulation* built from full-axis collectives plus
 static group reindexing (a gather of the group's rows / a scatter into
@@ -282,10 +285,11 @@ def grouped_all_gather(comm, x, *, tiled: bool = True):
     t = comm._group_tables()
     ax = _axis_of(comm)
     try:
-        return lax.all_gather(
-            x, ax, axis=0, tiled=tiled,
+        out = lax.all_gather(
+            x.reshape(-1), ax, axis=0, tiled=True,
             axis_index_groups=t.as_index_groups(),
-        )
+        ).reshape((t.group_size,) + tuple(x.shape))
+        return out.reshape((-1,) + tuple(x.shape[1:])) if tiled else out
     except NotImplementedError:
         full = lax.all_gather(x, ax, tiled=False)
         out = full[_my_members(comm, t)]
@@ -311,9 +315,9 @@ def grouped_all_to_all(comm, x):
         )
     try:
         return lax.all_to_all(
-            x, ax, split_axis=0, concat_axis=0, tiled=False,
+            x.reshape(g, -1), ax, split_axis=0, concat_axis=0, tiled=False,
             axis_index_groups=t.as_index_groups(),
-        )
+        ).reshape(x.shape)
     except NotImplementedError:
         mem = _my_members(comm, t)
         full = jnp.zeros((t.world,) + tuple(x.shape[1:]), x.dtype)
@@ -375,9 +379,9 @@ def grouped_psum_scatter(comm, x):
         )
     try:
         return lax.psum_scatter(
-            x, ax, scatter_dimension=0, tiled=False,
+            x.reshape(-1), ax, scatter_dimension=0, tiled=True,
             axis_index_groups=t.as_index_groups(),
-        )
+        ).reshape(x.shape[1:])
     except NotImplementedError:
         red = grouped_psum(comm, x)
         my = jnp.asarray(t.group_rank)[lax.axis_index(ax)]

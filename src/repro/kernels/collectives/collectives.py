@@ -33,11 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import allreduce_chunk as ref_chunk
 
-# Renamed upstream (TPUCompilerParams -> CompilerParams in newer jax).
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 __all__ = [
     "ring_allgather_pallas",
     "ring_reduce_scatter_pallas",
@@ -77,7 +72,7 @@ def ring_allgather_pallas(xs, *, interpret=None):
     out = pl.pallas_call(
         _allgather_kernel,
         grid=(p,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(
             (1, p, m), lambda r: (r, 0, 0), memory_space=pltpu.VMEM
         ),
@@ -126,7 +121,7 @@ def ring_reduce_scatter_pallas(xs, *, interpret=None):
     out = pl.pallas_call(
         _reduce_scatter_kernel,
         grid=(p,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(
             (1, m), lambda r: (r, 0), memory_space=pltpu.VMEM
         ),
@@ -185,7 +180,7 @@ def ring_alltoall_pallas(xs, *, interpret=None):
     out = pl.pallas_call(
         _alltoall_kernel,
         grid=(p,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(
             (1, p, m), lambda r: (r, 0, 0), memory_space=pltpu.VMEM
         ),
@@ -198,41 +193,74 @@ def ring_alltoall_pallas(xs, *, interpret=None):
 
 # --------------------------------------------------------------------------
 # Device kernels: per-chip RDMA ring (called inside shard_map on TPU)
+#
+# Payloads stay in HBM (``pl.ANY``): chunks move HBM to HBM over ICI, and
+# only the reduce-scatter's additions pass through VMEM, one bounded tile
+# at a time, so no payload size is limited by VMEM.  A flat payload is
+# laid out as (rows, 128) with rows a multiple of the tile, so every DMA
+# moves whole tiles.  Every ring step has its own semaphores and, in the
+# reduce-scatter, its own receive slot: no buffer or semaphore is reused
+# within a call, so a fast rank can never overwrite a slot its neighbour
+# is still sending from, and no per-step handshake is needed.
 # --------------------------------------------------------------------------
-def _neighbor_barrier(my_id, p):
+_LANES = 128
+# Sublane multiple that tiles every supported dtype (int8/bool need 32).
+_SUBLANES = 32
+# Rows per VMEM tile of the reduce-scatter's additions: 1024 x 128 x 4 B
+# = 512 KiB per buffer, two buffers.
+_TILE_ROWS = 1024
+
+
+def _ring_layout(m: int):
+    """(rows, tile_rows) of the (rows, 128) layout of an m-element chunk."""
+    rows = -(-max(m, 1) // _LANES)
+    rows = -(-rows // _SUBLANES) * _SUBLANES
+    tile = min(rows, _TILE_ROWS)
+    return -(-rows // tile) * tile, tile
+
+
+def _check_payload(name: str, dtype) -> None:
+    if jnp.dtype(dtype).itemsize not in (1, 2, 4):
+        from repro.core.errors import KampingError
+
+        raise KampingError(
+            f"{name}: the TPU ring kernels move 8-, 16- or 32-bit elements; "
+            f"got {jnp.dtype(dtype).name}"
+        )
+
+
+def _neighbor_barrier(axis, my_id, p):
     """Block until both ring neighbors reached this point (prevents a fast
-    rank's RDMA from landing before a slow neighbor allocated buffers)."""
+    rank's RDMA from landing before a slow neighbor entered the kernel)."""
     barrier = pltpu.get_barrier_semaphore()
     for nbr in (lax.rem(my_id + 1, p), lax.rem(my_id - 1 + p, p)):
         pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(nbr,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            barrier, inc=1, device_id={axis: nbr},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
     pltpu.semaphore_wait(barrier, 2)
 
 
-def _device_allgather_kernel(axis, p, x_ref, out_ref, send_sem, recv_sem):
+def _device_allgather_kernel(axis, p, x_hbm, out_hbm, local_sem, send_sem,
+                             recv_sem):
     my_id = lax.axis_index(axis)
-    m = x_ref.shape[0]
-    out_ref[pl.ds(my_id * m, m)] = x_ref[:]
-    _neighbor_barrier(my_id, p)
+    own = pltpu.make_async_copy(x_hbm, out_hbm.at[my_id], local_sem)
+    own.start()
+    own.wait()
+    _neighbor_barrier(axis, my_id, p)
     right = lax.rem(my_id + 1, p)
-
-    def step(s, carry):
+    for s in range(p - 1):
         src = lax.rem(my_id - s + p, p)  # chunk held after s hops
         rdma = pltpu.make_async_remote_copy(
-            src_ref=out_ref.at[pl.ds(src * m, m)],
-            dst_ref=out_ref.at[pl.ds(src * m, m)],
-            send_sem=send_sem.at[s % 2],
-            recv_sem=recv_sem.at[s % 2],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            src_ref=out_hbm.at[src],
+            dst_ref=out_hbm.at[src],
+            send_sem=send_sem.at[s],
+            recv_sem=recv_sem.at[s],
+            device_id={axis: right},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
         rdma.wait()
-        return carry
-
-    lax.fori_loop(0, p - 1, step, 0)
 
 
 def device_ring_allgather(x, axis, p: int, *, collective_id=7):
@@ -240,55 +268,87 @@ def device_ring_allgather(x, axis, p: int, *, collective_id=7):
     a TPU mesh.  x: (m,)-flattenable local chunk; returns the (p, ...)
     stacked gather.  CPU CI covers the schedule via the emulation kernel;
     this entry point is the ICI fast path."""
+    _check_payload("device_ring_allgather", x.dtype)
+    if x.dtype == jnp.bool_:
+        out = device_ring_allgather(
+            x.astype(jnp.int8), axis, p, collective_id=collective_id
+        )
+        return out.astype(jnp.bool_)
     shape = x.shape
     flat = x.reshape(-1)
     m = flat.shape[0]
+    rows, _ = _ring_layout(m)
+    x2 = jnp.pad(flat, (0, rows * _LANES - m)).reshape(rows, _LANES)
     out = pl.pallas_call(
         functools.partial(_device_allgather_kernel, axis, p),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((p * m,), flat.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((p, rows, _LANES), flat.dtype),
         scratch_shapes=[
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA((p - 1,)),
+            pltpu.SemaphoreType.DMA((p - 1,)),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True
         ),
-    )(flat)
-    return out.reshape((p,) + shape)
+    )(x2)
+    return out.reshape(p, rows * _LANES)[:, :m].reshape((p,) + shape)
 
 
-def _device_reduce_scatter_kernel(axis, p, x_ref, out_ref, buf, send_sem,
-                                  recv_sem):
+def _device_reduce_scatter_kernel(axis, p, tile, x_hbm, out_hbm, buf_hbm,
+                                  acc, mine, local_sem, send_sem, recv_sem):
     my_id = lax.axis_index(axis)
-    m = out_ref.shape[0]
-    # Start the partial for chunk (my_id - 1) % p: own contribution.
-    init = lax.rem(my_id - 1 + p, p)
-    buf[0] = x_ref[pl.ds(init * m, m)]
-    _neighbor_barrier(my_id, p)
-    right = lax.rem(my_id + 1, p)
+    n_tiles = out_hbm.shape[0] // tile
 
-    def step(s, carry):
-        send_slot = s % 2
-        recv_slot = (s + 1) % 2
+    def add_own(s, chunk, last):
+        """buf[s] + x[chunk] -> buf[s] (or the output on the last step),
+        staged through VMEM one tile at a time."""
+
+        def body(t, carry):
+            rows = pl.ds(pl.multiple_of(t * tile, tile), tile)
+            arrived = pltpu.make_async_copy(
+                buf_hbm.at[s, rows], acc, local_sem.at[0]
+            )
+            own = pltpu.make_async_copy(
+                x_hbm.at[chunk, rows], mine, local_sem.at[1]
+            )
+            arrived.start()
+            own.start()
+            arrived.wait()
+            own.wait()
+            a, b = acc[...], mine[...]
+            if jnp.issubdtype(a.dtype, jnp.integer) and a.dtype.itemsize == 1:
+                # Mosaic adds 16- and 32-bit integers only; the 8-bit sum
+                # wraps the same way when taken in 32 bits and truncated.
+                a, b = a.astype(jnp.int32), b.astype(jnp.int32)
+            acc[...] = (a + b).astype(acc.dtype)
+            dst = out_hbm.at[rows] if last else buf_hbm.at[s, rows]
+            back = pltpu.make_async_copy(acc, dst, local_sem.at[0])
+            back.start()
+            back.wait()
+            return carry
+
+        lax.fori_loop(0, n_tiles, body, 0)
+
+    _neighbor_barrier(axis, my_id, p)
+    right = lax.rem(my_id + 1, p)
+    # The partial for chunk (my_id - 1) % p starts here: own contribution.
+    send_from = x_hbm.at[lax.rem(my_id - 1 + p, p)]
+    for s in range(p - 1):
         rdma = pltpu.make_async_remote_copy(
-            src_ref=buf.at[send_slot],
-            dst_ref=buf.at[recv_slot],
-            send_sem=send_sem.at[send_slot],
-            recv_sem=recv_sem.at[recv_slot],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            src_ref=send_from,
+            dst_ref=buf_hbm.at[s],
+            send_sem=send_sem.at[s],
+            recv_sem=recv_sem.at[s],
+            device_id={axis: right},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
         rdma.wait()
         # The arrived partial is for chunk (my_id - 2 - s) % p; add ours.
-        dest = lax.rem(my_id - 2 - s + 2 * p, p)
-        buf[recv_slot] = buf[recv_slot] + x_ref[pl.ds(dest * m, m)]
-        return carry
-
-    lax.fori_loop(0, p - 1, step, 0)
-    out_ref[:] = buf[(p - 1) % 2]
+        add_own(s, lax.rem(my_id - 2 - s + 2 * p, p), last=s == p - 2)
+        send_from = buf_hbm.at[s]
 
 
 def device_ring_reduce_scatter(x, axis, p: int, *, collective_id=8):
@@ -296,21 +356,35 @@ def device_ring_reduce_scatter(x, axis, p: int, *, collective_id=8):
     shard_map on a TPU mesh.  x: (p, chunk...) contributions by
     destination; returns this rank's reduced chunk, accumulated in the
     canonical ring order shared with ref.py / the emulation kernel."""
+    _check_payload("device_ring_reduce_scatter", x.dtype)
     shape = x.shape[1:]
     flat = x.reshape(p, -1)
     m = flat.shape[1]
-    out = pl.pallas_call(
-        functools.partial(_device_reduce_scatter_kernel, axis, p),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m,), flat.dtype),
+    rows, tile = _ring_layout(m)
+    x3 = jnp.pad(flat, ((0, 0), (0, rows * _LANES - m))).reshape(
+        p, rows, _LANES
+    )
+    out, _ = pl.pallas_call(
+        functools.partial(_device_reduce_scatter_kernel, axis, p, tile),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((rows, _LANES), flat.dtype),
+            # one receive slot per ring step
+            jax.ShapeDtypeStruct((p - 1, rows, _LANES), flat.dtype),
+        ),
         scratch_shapes=[
-            pltpu.VMEM((2, m), flat.dtype),
+            pltpu.VMEM((tile, _LANES), flat.dtype),
+            pltpu.VMEM((tile, _LANES), flat.dtype),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((p - 1,)),
+            pltpu.SemaphoreType.DMA((p - 1,)),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True
         ),
-    )(flat.reshape(-1))
-    return out.reshape(shape)
+    )(x3)
+    return out.reshape(-1)[:m].reshape(shape)

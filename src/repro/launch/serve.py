@@ -55,9 +55,11 @@ def main(argv=None):
     import numpy as np
 
     from repro.configs import get_config
+    from repro.launch.cache import enable_compilation_cache
     from repro.models import init_params
     from repro.serve import Request, ServeEngine
 
+    enable_compilation_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_params(cfg, jax.random.PRNGKey(0))
     engine = ServeEngine(cfg, params, max_len=args.max_len,

@@ -74,11 +74,13 @@ def main(argv=None):
     from repro.configs import get_config
     from repro.core.ulfm import WorldComm
     from repro.data import ByteCorpus, PackedLM, SyntheticLM
+    from repro.launch.cache import enable_compilation_cache
     from repro.launch.mesh import make_host_mesh
     from repro.sharding import ShardingProfile
     from repro.train import (AdamWConfig, FaultTolerantRunner, TrainConfig,
                              Trainer)
 
+    enable_compilation_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     over = {}
     if args.num_layers:
@@ -187,8 +189,8 @@ def main(argv=None):
     for i in range(start, args.steps):
         batch = trainer.place_batch(next(data))
         t0 = time.perf_counter()
-        params, opt_state, extra, loss, metrics = step_fn(
-            params, opt_state, extra, batch
+        params, opt_state, extra, loss, metrics = jax.block_until_ready(
+            step_fn(params, opt_state, extra, batch)
         )
         if i % args.log_every == 0 or i == args.steps - 1:
             dt = time.perf_counter() - t0

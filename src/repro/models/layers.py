@@ -1,6 +1,7 @@
 """Shared neural building blocks (functional style, explicit param pytrees)."""
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -18,6 +19,7 @@ __all__ = [
     "gated_mlp",
     "causal_conv1d",
     "chunked_attention",
+    "flash_attention",
     "decode_attention",
     "init_attention",
     "attention_forward",
@@ -186,6 +188,34 @@ def chunked_attention(
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention(q, k, v, causal, window, chunk):
+    """The Pallas flash-attention kernel, differentiable.
+
+    The kernel has no backward pass of its own: the gradient is that of
+    :func:`chunked_attention`, which computes the same function in XLA."""
+    from repro.kernels.flash_attention import ops as flash_ops
+
+    return flash_ops.flash_attention(q, k, v, causal=causal, window=window)
+
+
+def _flash_attention_fwd(q, k, v, causal, window, chunk):
+    return flash_attention(q, k, v, causal, window, chunk), (q, k, v)
+
+
+def _flash_attention_bwd(causal, window, chunk, res, g):
+    _, vjp = jax.vjp(
+        functools.partial(
+            chunked_attention, causal=causal, window=window, chunk=chunk
+        ),
+        *res,
+    )
+    return vjp(g)
+
+
+flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
 def decode_attention(q, k_cache, v_cache, pos, *, window: Optional[int] = None):
     """Single-token attention against a (B, T, KV, D) cache.
 
@@ -248,9 +278,7 @@ def attention_forward(p, x, cfg, *, window=None, causal=True, kv=None,
         k, v = kv
         causal = False
     if cfg.use_pallas and jax.default_backend() == "tpu":
-        from repro.kernels.flash_attention import ops as flash_ops
-
-        out = flash_ops.flash_attention(q, k, v, causal=causal, window=window)
+        out = flash_attention(q, k, v, causal, window, cfg.attn_chunk)
     else:
         out = chunked_attention(
             q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk

@@ -270,6 +270,13 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
     dp_axes = profile.dp_axes
     dp_name = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     dp_set = set(dp_axes)
+    # The island is manual over the dp axes, and over every other mesh
+    # axis when all of those have size 1: GSPMD cannot partition a Pallas
+    # kernel, even over an axis of size 1.
+    if all(mesh.shape[a] == 1 for a in mesh.axis_names if a not in dp_set):
+        manual_axes = set(mesh.axis_names)
+    else:
+        manual_axes = dp_set
 
     # Transport resolution (DESIGN.md §7/§9): "hier" with explicit knobs
     # becomes a configured HierTransport instance (two-level reduction:
@@ -439,7 +446,7 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
                 mesh=mesh,
                 in_specs=(pspec, bspec, espec),
                 out_specs=(pspec, espec, P(profile.dp)),
-                axis_names=dp_set,
+                axis_names=manual_axes,
                 check_vma=False,
             )(params, batch, extra)
             loss = jnp.mean(loss)
@@ -453,7 +460,7 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
                 mesh=mesh,
                 in_specs=(pspec, bspec),
                 out_specs=(pspec, P(profile.dp)),
-                axis_names=dp_set,
+                axis_names=manual_axes,
                 check_vma=False,
             )(params, batch)
             new_extra = extra

@@ -5,7 +5,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
-import repro  # noqa: F401 — installs the jax forward-compat backfill
 import jax
 import numpy as np
 import pytest

@@ -23,17 +23,6 @@ CFG = ModelConfig(
     param_dtype="float32",
 )
 
-# The manual-DP grad-reduce modes stage a shard_map manual over the dp
-# axes only (partial-auto).  On a jax that predates native jax.shard_map
-# the compat backfill maps this to the experimental legacy `auto=` param,
-# whose XLA CPU compile aborts the process — skip rather than crash.
-_PARTIAL_AUTO_OK = not getattr(jax.shard_map, "_repro_backfill", False)
-needs_partial_auto = pytest.mark.skipif(
-    not _PARTIAL_AUTO_OK,
-    reason="partial-auto shard_map (axis_names=) unsupported on this jax",
-)
-
-
 def _mesh(devs=None):
     devs = devs if devs is not None else jax.devices()
     n = len(devs)
@@ -63,15 +52,14 @@ def _run(mode, mb, fsdp, steps=25, grad_compress=None):
 @pytest.mark.parametrize("mode,mb,fsdp", [
     ("auto", 1, True),
     ("auto", 2, True),
-    pytest.param("compressed", 1, False, marks=needs_partial_auto),
-    pytest.param("reproducible", 4, False, marks=needs_partial_auto),
+    ("compressed", 1, False),
+    ("reproducible", 4, False),
 ])
 def test_training_converges(mode, mb, fsdp):
     hist = _run(mode, mb, fsdp)
     assert hist[-1][1] < hist[0][1] - 0.5, (mode, hist)
 
 
-@needs_partial_auto
 def test_training_converges_reproducible_compressed():
     """grad_reduce="reproducible" + grad_compress="int8-ef": the
     quantized-leaf deterministic path (DESIGN.md §12) still learns."""
@@ -79,7 +67,6 @@ def test_training_converges_reproducible_compressed():
     assert hist[-1][1] < hist[0][1] - 0.5, hist
 
 
-@needs_partial_auto
 @pytest.mark.parametrize("grad_compress", [None, "int8-ef"])
 def test_reproducible_training_bitwise_across_p(grad_compress):
     """The ISSUE-7 acceptance gate at the real-Trainer level: a short
@@ -117,7 +104,6 @@ def test_reproducible_training_bitwise_across_p(grad_compress):
             np.testing.assert_array_equal(a, b)
 
 
-@needs_partial_auto
 def test_grad_reduce_modes_agree():
     """auto vs reproducible must produce (near-)identical trajectories;
     compressed is within quantization tolerance."""

@@ -21,7 +21,12 @@ from repro.kernels.collectives import (
     ring_alltoall_stacked,
     ring_reduce_scatter_stacked,
 )
+from repro.core.errors import KampingError
 from repro.kernels.collectives import ref
+from repro.kernels.collectives.collectives import (
+    device_ring_allgather,
+    device_ring_reduce_scatter,
+)
 
 PS = (1, 2, 4, 8)
 
@@ -70,6 +75,15 @@ def test_kernel_alltoall_matches_oracle(p):
     np.testing.assert_array_equal(
         np.asarray(out), ref.alltoall_stacked_ref(xs)
     )
+
+
+@pytest.mark.parametrize("kernel", [device_ring_allgather,
+                                    device_ring_reduce_scatter])
+def test_device_ring_kernels_refuse_wide_elements(p, kernel):
+    """The TPU ring kernels move 8-, 16- and 32-bit elements: a wider
+    payload is refused at trace time, not handed to another path."""
+    with pytest.raises(KampingError, match="8-, 16- or 32-bit"):
+        kernel(np.zeros((p, 4), np.complex64), "x", p)
 
 
 def test_kernel_allreduce_uneven_payload(p):

@@ -95,3 +95,30 @@ def test_lru_decay_stability_long_sequence():
     out = np.asarray(lru_scan_pallas(a, b, block_t=128, block_c=16, interpret=True))
     assert np.isfinite(out).all()
     assert (np.abs(out) <= 0.01 / (1 - 0.999) + 1e-3).all()
+
+
+def test_flash_attention_gradient_is_chunked_attentions():
+    """The model's differentiable kernel (models.layers.flash_attention):
+    the forward is the kernel, the gradient that of chunked_attention."""
+    import jax
+
+    from repro.models.layers import chunked_attention, flash_attention
+
+    q = RNG.randn(2, 128, 6, 64).astype(np.float32)
+    k = RNG.randn(2, 128, 2, 64).astype(np.float32)
+    v = RNG.randn(2, 128, 2, 64).astype(np.float32)
+    g = RNG.randn(2, 128, 6, 64).astype(np.float32)
+
+    def kernel_loss(q, k, v):
+        return (flash_attention(q, k, v, True, None, 64) * g).sum()
+
+    def xla_loss(q, k, v):
+        return (chunked_attention(q, k, v, causal=True, chunk=64) * g).sum()
+
+    np.testing.assert_allclose(
+        float(kernel_loss(q, k, v)), float(xla_loss(q, k, v)), rtol=1e-5
+    )
+    got = jax.grad(kernel_loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(xla_loss, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
