@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -424,30 +425,35 @@ def execute(comm, spec: OpSpec, args, kw=None):
     if spec.bucketed:
         _validate_and_resize_buckets(low)
 
-    buf = spec.lower(low)
+    # Every instruction the op stages (its out-fields and count check
+    # too) carries ``kamping.<op>`` in its op_name, so a profile of any
+    # program groups device time by collective.
+    with jax.named_scope(f"kamping.{spec.name}"):
+        buf = spec.lower(low)
 
-    out_fields = [("recv_buf", buf)]
-    for param in pack.values():  # request order == result unpack order
-        field = _OUT_FIELDS.get(param.kind)
-        if field is not None and param.is_out:
-            out_fields.append((field, low.resolve(field)))
-    if low._codec_has_state:
-        # Error-feedback round-trip (DESIGN.md §10): state went in on the
-        # compression(...) parameter, the new residual comes back on the
-        # result.  A None codec (explicit disable) echoes the state.
-        out_fields.append((
-            "compression_state",
-            low._codec_new_state if low._codec_new_state is not None
-            else low._codec_state,
-        ))
+        out_fields = [("recv_buf", buf)]
+        for param in pack.values():  # request order == result unpack order
+            field = _OUT_FIELDS.get(param.kind)
+            if field is not None and param.is_out:
+                out_fields.append((field, low.resolve(field)))
+        if low._codec_has_state:
+            # Error-feedback round-trip (DESIGN.md §10): state went in on
+            # the compression(...) parameter, the new residual comes back
+            # on the result.  A None codec (explicit disable) echoes the
+            # state.
+            out_fields.append((
+                "compression_state",
+                low._codec_new_state if low._codec_new_state is not None
+                else low._codec_state,
+            ))
 
-    if (
-        spec.heavy_count_check
-        and check_enabled(AssertionLevel.HEAVY)
-        and low.has(K.SEND_COUNTS)
-    ):
-        buf = _stage_global_count_check(low, buf)
-        out_fields[0] = ("recv_buf", buf)
+        if (
+            spec.heavy_count_check
+            and check_enabled(AssertionLevel.HEAVY)
+            and low.has(K.SEND_COUNTS)
+        ):
+            buf = _stage_global_count_check(low, buf)
+            out_fields[0] = ("recv_buf", buf)
 
     rec = ir.active()
     if rec is not None:

@@ -79,6 +79,7 @@ def ring_allgather_pallas(xs, *, interpret=None):
         out_shape=jax.ShapeDtypeStruct((p, p, m), x2.dtype),
         scratch_shapes=[pltpu.VMEM((m,), x2.dtype), pltpu.SemaphoreType.DMA],
         interpret=interpret,
+        name="ring_allgather",
     )(x2)
     return out.reshape((p, p) + xs.shape[1:])
 
@@ -132,6 +133,7 @@ def ring_reduce_scatter_pallas(xs, *, interpret=None):
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        name="ring_reduce_scatter",
     )(x3)
     return out.reshape((p,) + xs.shape[2:])
 
@@ -187,6 +189,7 @@ def ring_alltoall_pallas(xs, *, interpret=None):
         out_shape=jax.ShapeDtypeStruct((p, p, m), x3.dtype),
         scratch_shapes=[pltpu.VMEM((m,), x3.dtype), pltpu.SemaphoreType.DMA],
         interpret=interpret,
+        name="ring_alltoall",
     )(x3)
     return out.reshape((p, p) + xs.shape[2:])
 
@@ -292,6 +295,7 @@ def device_ring_allgather(x, axis, p: int, *, collective_id=7):
         compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True
         ),
+        name="device_ring_allgather",
     )(x2)
     return out.reshape(p, rows * _LANES)[:, :m].reshape((p,) + shape)
 
@@ -386,5 +390,6 @@ def device_ring_reduce_scatter(x, axis, p: int, *, collective_id=8):
         compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True
         ),
+        name="device_ring_reduce_scatter",
     )(x3)
     return out.reshape(-1)[:m].reshape(shape)

@@ -129,6 +129,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     if pad_q:
         out = out[:, :, :Sq, :]

@@ -79,5 +79,6 @@ def lru_scan_pallas(a, b, *, block_t=256, block_c=512, interpret=False):
         out_shape=jax.ShapeDtypeStruct((Bsz, S, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
+        name="rg_lru_scan",
     )(a, b)
     return out

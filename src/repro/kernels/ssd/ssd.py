@@ -104,5 +104,6 @@ def ssd_scan_pallas(x, a, Bm, C, *, chunk=128, interpret=False):
         out_shape=jax.ShapeDtypeStruct(xt.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xt, la[..., None], la[:, :, None, :], bt, ct)
     return jnp.moveaxis(out, 1, 2)

@@ -204,13 +204,16 @@ def _flash_attention_fwd(q, k, v, causal, window, chunk):
 
 
 def _flash_attention_bwd(causal, window, chunk, res, g):
-    _, vjp = jax.vjp(
-        functools.partial(
-            chunked_attention, causal=causal, window=window, chunk=chunk
-        ),
-        *res,
-    )
-    return vjp(g)
+    # Not a kernel, so no instruction name finds it in a profile: the
+    # scope does.
+    with jax.named_scope("attn.flash_bwd"):
+        _, vjp = jax.vjp(
+            functools.partial(
+                chunked_attention, causal=causal, window=window, chunk=chunk
+            ),
+            *res,
+        )
+        return vjp(g)
 
 
 flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)

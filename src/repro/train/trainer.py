@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import (
     Communicator,
     compression,
@@ -162,10 +163,15 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
                     profile: ShardingProfile, mesh):
     """Returns train_step(params, opt_state, extra_state, batch)."""
 
+    # Named scopes put each phase into its instructions' op_name, which a
+    # profile groups by: ``train.forward``, its backward as
+    # ``transpose(jvp(train.forward))``, ``train.reduce`` and
+    # ``train.optimizer``.
     def loss_fn(params, batch):
-        return loss_and_metrics(
-            params, batch, cfg, runtime, aux_weight=tcfg.aux_weight
-        )
+        with jax.named_scope("train.forward"):
+            return loss_and_metrics(
+                params, batch, cfg, runtime, aux_weight=tcfg.aux_weight
+            )
 
     if tcfg.grad_reduce not in ("auto", "allreduce", "overlap",
                                 "reproducible"):
@@ -259,9 +265,10 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
                 (loss, metrics), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(params, batch)
-            new_params, new_opt, opt_metrics = adamw_update(
-                tcfg.opt, grads, opt_state, cfg.param_dtype
-            )
+            with jax.named_scope("train.optimizer"):
+                new_params, new_opt, opt_metrics = adamw_update(
+                    tcfg.opt, grads, opt_state, cfg.param_dtype
+                )
             return new_params, new_opt, extra, loss, {**(metrics or {}), **opt_metrics}
 
         return train_step
@@ -337,58 +344,59 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
                 (loss, _), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(params, batch)
-            comm = Communicator(dp_name, transport=grad_transport)
-            inv_p = 1.0 / comm.size()
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope("train.reduce"):
+                comm = Communicator(dp_name, transport=grad_transport)
+                inv_p = 1.0 / comm.size()
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
 
-            new_err = None
-            if tcfg.grad_reduce == "overlap":
-                if grad_codec is not None:
-                    grads, new_err = overlap_reduce_tree(
-                        comm, grads,
-                        bucket_bytes=tcfg.bucket_bytes,
-                        max_inflight=tcfg.max_inflight,
-                        mode=tcfg.overlap_mode,
-                        scale=inv_p,
-                        compression=grad_codec,
-                        err_state=err,
-                        deterministic=tcfg.deterministic,
-                        plan=tcfg.plan,
-                    )
+                new_err = None
+                if tcfg.grad_reduce == "overlap":
+                    if grad_codec is not None:
+                        grads, new_err = overlap_reduce_tree(
+                            comm, grads,
+                            bucket_bytes=tcfg.bucket_bytes,
+                            max_inflight=tcfg.max_inflight,
+                            mode=tcfg.overlap_mode,
+                            scale=inv_p,
+                            compression=grad_codec,
+                            err_state=err,
+                            deterministic=tcfg.deterministic,
+                            plan=tcfg.plan,
+                        )
+                    else:
+                        grads = overlap_reduce_tree(
+                            comm, grads,
+                            bucket_bytes=tcfg.bucket_bytes,
+                            max_inflight=tcfg.max_inflight,
+                            mode=tcfg.overlap_mode,
+                            scale=inv_p,
+                            deterministic=tcfg.deterministic,
+                            plan=tcfg.plan,
+                        )
+                elif grad_codec is not None:
+                    flat_g, gdef = jax.tree.flatten(grads)
+                    flat_e = gdef.flatten_up_to(err)
+
+                    def reduce_leaf(g, e):
+                        # every leaf is float32 here (cast above), so the
+                        # codec applies unconditionally
+                        r = comm.allreduce(
+                            send_buf(g), op(operator.add),
+                            compression(grad_codec, state=e),
+                        )
+                        return r.recv_buf * inv_p, r.compression_state
+
+                    out = [reduce_leaf(g, e) for g, e in zip(flat_g, flat_e)]
+                    grads = jax.tree.unflatten(gdef, [o[0] for o in out])
+                    new_err = jax.tree.unflatten(gdef, [o[1] for o in out])
                 else:
-                    grads = overlap_reduce_tree(
-                        comm, grads,
-                        bucket_bytes=tcfg.bucket_bytes,
-                        max_inflight=tcfg.max_inflight,
-                        mode=tcfg.overlap_mode,
-                        scale=inv_p,
-                        deterministic=tcfg.deterministic,
-                        plan=tcfg.plan,
+                    grads = jax.tree.map(
+                        lambda g: comm.allreduce(
+                            send_buf(g), op(operator.add)
+                        ) * inv_p,
+                        grads,
                     )
-            elif grad_codec is not None:
-                flat_g, gdef = jax.tree.flatten(grads)
-                flat_e = gdef.flatten_up_to(err)
-
-                def reduce_leaf(g, e):
-                    # every leaf is float32 here (cast above), so the
-                    # codec applies unconditionally
-                    r = comm.allreduce(
-                        send_buf(g), op(operator.add),
-                        compression(grad_codec, state=e),
-                    )
-                    return r.recv_buf * inv_p, r.compression_state
-
-                out = [reduce_leaf(g, e) for g, e in zip(flat_g, flat_e)]
-                grads = jax.tree.unflatten(gdef, [o[0] for o in out])
-                new_err = jax.tree.unflatten(gdef, [o[1] for o in out])
-            else:
-                grads = jax.tree.map(
-                    lambda g: comm.allreduce(
-                        send_buf(g), op(operator.add)
-                    ) * inv_p,
-                    grads,
-                )
-            loss = jax.lax.pmean(loss, dp_name)
+                loss = jax.lax.pmean(loss, dp_name)
             return grads, new_err, loss
         # reproducible: alias for allreduce + the engine-level
         # deterministic("tree", leaves=microbatches) parameter
@@ -399,33 +407,34 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
         # transport (the tree is pure ppermute) and, with a quantized
         # codec, over the quantized leaf partials (exact accumulation).
         stacked, losses = microbatch_grads(params, batch)
-        comm = Communicator(dp_name, transport=grad_transport)
-        denom = tcfg.microbatches * comm.size()
-        det = deterministic("tree", leaves=tcfg.microbatches)
+        with jax.named_scope("train.reduce"):
+            comm = Communicator(dp_name, transport=grad_transport)
+            denom = tcfg.microbatches * comm.size()
+            det = deterministic("tree", leaves=tcfg.microbatches)
 
-        new_err = None
-        if grad_codec is not None:
-            flat_g, gdef = jax.tree.flatten(stacked)
-            flat_e = gdef.flatten_up_to(err)
+            new_err = None
+            if grad_codec is not None:
+                flat_g, gdef = jax.tree.flatten(stacked)
+                flat_e = gdef.flatten_up_to(err)
 
-            def reduce_leaf_c(g, e):
-                r = comm.allreduce(
-                    send_buf(g), op(operator.add), det,
-                    compression(grad_codec, state=e),
+                def reduce_leaf_c(g, e):
+                    r = comm.allreduce(
+                        send_buf(g), op(operator.add), det,
+                        compression(grad_codec, state=e),
+                    )
+                    return r.recv_buf / denom, r.compression_state
+
+                out = [reduce_leaf_c(g, e) for g, e in zip(flat_g, flat_e)]
+                grads = jax.tree.unflatten(gdef, [o[0] for o in out])
+                new_err = jax.tree.unflatten(gdef, [o[1] for o in out])
+            else:
+                grads = jax.tree.map(
+                    lambda g: comm.allreduce(
+                        send_buf(g), op(operator.add), det
+                    ) / denom,
+                    stacked,
                 )
-                return r.recv_buf / denom, r.compression_state
-
-            out = [reduce_leaf_c(g, e) for g, e in zip(flat_g, flat_e)]
-            grads = jax.tree.unflatten(gdef, [o[0] for o in out])
-            new_err = jax.tree.unflatten(gdef, [o[1] for o in out])
-        else:
-            grads = jax.tree.map(
-                lambda g: comm.allreduce(
-                    send_buf(g), op(operator.add), det
-                ) / denom,
-                stacked,
-            )
-        loss = jax.lax.pmean(jnp.mean(losses), dp_name)
+            loss = jax.lax.pmean(jnp.mean(losses), dp_name)
         return grads, new_err, loss
 
     def train_step(params, opt_state, extra, batch):
@@ -465,9 +474,10 @@ def make_train_step(cfg, tcfg: TrainConfig, runtime: Runtime,
             )(params, batch)
             new_extra = extra
             loss = jnp.mean(loss)
-        new_params, new_opt, opt_metrics = adamw_update(
-            tcfg.opt, grads, opt_state, cfg.param_dtype
-        )
+        with jax.named_scope("train.optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                tcfg.opt, grads, opt_state, cfg.param_dtype
+            )
         return new_params, new_opt, new_extra, loss, opt_metrics
 
     return train_step
@@ -490,6 +500,7 @@ class Trainer:
             force_moe_mode=profile.moe_mode if profile.moe_mode != "ep_alltoall" else None,
         )
         self._step_fn = None
+        obs.install()  # the compile log of init_state and train_step
 
     # -- state ----------------------------------------------------------------
     def dp_size(self) -> int:
@@ -506,14 +517,14 @@ class Trainer:
         (the elastic reshard onto the current mesh)."""
         from repro.models import init_params
 
-        def init():
+        def init_state():
             params = init_params(
                 self.cfg, key if key is not None else jax.random.PRNGKey(0),
                 ep_size,
             )
             return params, adamw_init(params)
 
-        params_shape = jax.eval_shape(init)
+        params_shape = jax.eval_shape(init_state)
         pspecs = param_specs(
             params_shape[0], self.cfg, self.profile, self.mesh
         )
@@ -523,7 +534,7 @@ class Trainer:
             "mu": pspecs,
             "nu": pspecs,
         }
-        return init, pspecs, ospecs
+        return init_state, pspecs, ospecs
 
     def init_state(self, key, ep_size: int = 1):
         init, pspecs, ospecs = self._state_specs(key, ep_size)

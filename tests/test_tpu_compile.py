@@ -10,6 +10,7 @@ the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +60,15 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel):
+    """Compile ``fn``; its program holds the Pallas kernel under the
+    instruction name ``kernel`` (``%<kernel>`` or ``%<kernel>.<n>``), the
+    name a profile of the chip reports it by."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), "kernel not in program"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel not in program"
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call", text), \
+        f"no custom call named {kernel!r}"
     return compiled
 
 
@@ -81,7 +88,8 @@ def test_flash_attention_compiles_at_smollm_widths(one_chip, monkeypatch):
         out = flash_attention(q, k, v, True, None, 512)
         return out.astype(jnp.float32).sum()
 
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+             kernel="flash_fwd")
 
 
 def test_ssd_compiles_at_mamba2_370m_widths(one_chip):
@@ -100,7 +108,8 @@ def test_ssd_compiles_at_mamba2_370m_widths(one_chip):
         _spec((B, S, G, N), dt, one_chip),
         _spec((B, S, G, N), dt, one_chip),
     )
-    _compile(functools.partial(ssd_scan_pallas, chunk=cfg.ssm_chunk), *args)
+    _compile(functools.partial(ssd_scan_pallas, chunk=cfg.ssm_chunk), *args,
+             kernel="ssd_scan")
 
 
 def test_rg_lru_compiles_at_recurrentgemma_widths(one_chip):
@@ -110,7 +119,7 @@ def test_rg_lru_compiles_at_recurrentgemma_widths(one_chip):
 
     w = get_config("recurrentgemma-9b").lru_width
     a = _spec((2, 2048, w), jnp.float32, one_chip)
-    _compile(lru_scan_pallas, a, a)
+    _compile(lru_scan_pallas, a, a, kernel="rg_lru_scan")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
@@ -136,4 +145,4 @@ def test_ring_kernels_compile_on_four_chips(ring_mesh, kernel, dtype):
     fn = jax.shard_map(body, mesh=ring_mesh, in_specs=P("x"),
                        out_specs=P("x"), check_vma=False)
     x = _spec((p * local,), dtype, NamedSharding(ring_mesh, P("x")))
-    _compile(fn, x)
+    _compile(fn, x, kernel=f"device_ring_{kernel}")
