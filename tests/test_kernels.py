@@ -4,7 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention.flash_attention import (
+    flash_attention_pallas,
+    tile_plan,
+)
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rg_lru.ref import lru_sequential_ref, rglru_scan_ref
 from repro.kernels.rg_lru.rg_lru import lru_scan_pallas
@@ -14,27 +17,116 @@ from repro.kernels.ssd.ssd import ssd_scan_pallas
 RNG = np.random.RandomState(42)
 
 
+def _flash_case(*shape, blocks=(64, 64)):
+    """A case named by its shape, and by its blocks where not 64 × 64
+    (``None``: the kernel's own tile plan)."""
+    name = "-".join(map(str, shape))
+    if blocks != (64, 64):
+        name += "-bq{}-bk{}".format(*blocks)
+    return pytest.param(*shape, *blocks, id=name)
+
+
 @pytest.mark.parametrize(
-    "B,Sq,Skv,H,KV,D,causal,window",
+    "B,Sq,Skv,H,KV,D,causal,window,block_q,block_k",
     [
-        (2, 128, 128, 4, 2, 64, True, None),
-        (1, 256, 256, 4, 1, 32, True, 48),     # MQA + sliding window
-        (2, 100, 100, 2, 2, 64, True, None),   # non-multiple -> padding
-        (1, 64, 192, 4, 4, 64, False, None),   # cross-attention style
-        (1, 128, 128, 8, 2, 128, True, 32),    # GQA 4:1, small window
+        _flash_case(2, 128, 128, 4, 2, 64, True, None),
+        _flash_case(1, 256, 256, 4, 1, 32, True, 48),    # MQA + sliding window
+        _flash_case(2, 100, 100, 2, 2, 64, True, None),  # non-multiple -> padding
+        _flash_case(1, 64, 192, 4, 4, 64, False, None),  # cross-attention style
+        _flash_case(1, 128, 128, 8, 2, 128, True, 32),   # GQA 4:1, small window
+        # smollm-360m's grouping: 15 query heads over 5 KV heads of 64
+        _flash_case(1, 512, 512, 15, 5, 64, True, None),
+        _flash_case(1, 300, 300, 15, 5, 64, True, None, blocks=(None, None)),
+        # unequal blocks, both ways: dead, diagonal and interior tiles
+        _flash_case(1, 512, 512, 6, 2, 64, True, None, blocks=(128, 256)),
+        _flash_case(1, 512, 512, 6, 2, 64, True, None, blocks=(256, 128)),
+        # a window across block edges: leading tiles are skipped
+        _flash_case(1, 512, 512, 4, 2, 64, True, 100, blocks=(128, 64)),
+        _flash_case(1, 256, 256, 4, 2, 32, False, 80),
+        _flash_case(1, 256, 64, 2, 1, 32, False, 32),    # queries that see no key
+        # non-causal, Skv not a multiple of block_k
+        _flash_case(2, 96, 200, 6, 3, 64, False, None, blocks=(32, 64)),
     ],
 )
-def test_flash_attention_matches_ref(B, Sq, Skv, H, KV, D, causal, window):
+def test_flash_attention_matches_ref(B, Sq, Skv, H, KV, D, causal, window,
+                                     block_q, block_k):
     q = RNG.randn(B, Sq, H, D).astype(np.float32)
     k = RNG.randn(B, Skv, KV, D).astype(np.float32)
     v = RNG.randn(B, Skv, KV, D).astype(np.float32)
     out = flash_attention_pallas(
-        q, k, v, causal=causal, window=window, block_q=64, block_k=64,
-        interpret=True,
+        q, k, v, causal=causal, window=window, block_q=block_q,
+        block_k=block_k, interpret=True,
     )
     ref = attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def _live_tiles_brute_force(Sq, Skv, block_q, block_k, causal, window):
+    q_pos = np.arange(Sq)[:, None]
+    k_pos = np.arange(Skv)[None, :]
+    mask = np.ones((Sq, Skv), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    return sum(
+        mask[i:i + block_q, j:j + block_k].any()
+        for i in range(0, Sq, block_q) for j in range(0, Skv, block_k)
+    )
+
+
+@pytest.mark.parametrize(
+    "Sq,Skv,causal,window,block_q,block_k",
+    [
+        (2048, 2048, True, None, None, None),
+        (512, 512, True, None, 128, 256),
+        (512, 512, True, None, 256, 128),
+        (300, 300, True, None, 64, 64),
+        (512, 512, True, 100, 128, 64),
+        (4096, 4096, True, 1024, None, None),
+        (256, 256, False, 80, 64, 64),
+        (256, 64, False, 32, 64, 64),
+        (96, 200, False, None, 32, 64),
+        (200, 96, True, None, 64, 32),
+    ],
+)
+def test_tile_plan_counts_live_tiles(Sq, Skv, causal, window, block_q, block_k):
+    """Live tiles are exactly those with an unmasked query/key pair."""
+    B, H, KV = 2, 6, 2
+    plan = tile_plan(B, Sq, Skv, H, KV, 64, causal, window, block_q, block_k)
+    n_q, n_k = -(-Sq // plan.block_q), -(-Skv // plan.block_k)
+    assert plan.grid == (B, KV, n_q, n_k)
+    want = _live_tiles_brute_force(Sq, Skv, plan.block_q, plan.block_k,
+                                   causal, window)
+    assert plan.live_tiles == B * KV * want
+
+
+def test_tile_plan_folds_query_groups_at_smollm_shape():
+    """smollm-360m training: one grid step per KV head, 512 × 1024
+    blocks, 240 of the 320 steps live under the causal mask."""
+    plan = tile_plan(8, 2048, 2048, 15, 5, 64, causal=True)
+    assert plan == (512, 1024, (8, 5, 4, 2), 240)
+    # a short prefill bucket keeps a single block
+    assert tile_plan(1, 64, 64, 15, 5, 64).grid == (1, 5, 1, 1)
+
+
+@pytest.mark.parametrize(
+    "H,KV,D,block_q,block_k",
+    [
+        (15, 5, 64, 512, 1024),    # smollm-360m
+        (48, 8, 128, 256, 1024),   # mixtral-8x22b
+        (96, 8, 128, 128, 1024),   # mistral-large-123b
+        (16, 16, 64, 512, 1024),   # whisper-medium
+        (16, 1, 256, 32, 1024),    # recurrentgemma-9b: wide heads, fewer rows
+    ],
+)
+def test_tile_plan_rows_shrink_with_head_width(H, KV, D, block_q, block_k):
+    """A step's query rows times their lane-padded width stay within what
+    1536 rows of a head up to 128 wide take."""
+    plan = tile_plan(1, 4096, 4096, H, KV, D)
+    assert (plan.block_q, plan.block_k) == (block_q, block_k)
+    assert H // KV * block_q * max(D, 128) <= 1536 * 128
 
 
 @pytest.mark.parametrize("dtype,atol", [(np.float32, 2e-5), (jnp.bfloat16, 3e-2)])
@@ -49,6 +141,22 @@ def test_flash_attention_dtypes(dtype, atol):
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=atol, rtol=atol,
     )
+
+
+def test_flash_attention_bf16_rounds_output_once():
+    """With bf16 inputs the output is the exact attention rounded once to
+    bf16: ``p·v`` loses none of the float32 probabilities' precision."""
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(2 * rng.randn(1, 512, 6, 64), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(1, 512, 2, 64), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(1, 512, 2, 64), jnp.bfloat16)
+    exact = np.asarray(attention_ref(
+        *(x.astype(jnp.float32) for x in (q, k, v))))
+    out = np.asarray(flash_attention_pallas(
+        q, k, v, block_q=128, block_k=128, interpret=True), np.float32)
+    once = np.asarray(jnp.asarray(exact, jnp.bfloat16), np.float32)
+    # Probabilities rounded to bf16 before p·v give 1.24 here.
+    assert np.abs(out - exact).mean() <= 1.02 * np.abs(once - exact).mean()
 
 
 @pytest.mark.parametrize(

@@ -84,12 +84,47 @@ def test_flash_attention_compiles_at_smollm_widths(one_chip, monkeypatch):
     q = _spec((8, 2048, 15, 64), jnp.bfloat16, one_chip)
     kv = _spec((8, 2048, 5, 64), jnp.bfloat16, one_chip)
 
-    def loss(q, k, v):
-        out = flash_attention(q, k, v, True, None, 512)
-        return out.astype(jnp.float32).sum()
+    def forward(q, k, v):
+        return flash_attention(q, k, v, True, None, 512)
 
+    def loss(q, k, v):
+        return forward(q, k, v).astype(jnp.float32).sum()
+
+    # One kernel call per attention call: the tiling is one pallas_call.
+    text = _compile(forward, q, kv, kv, kernel="flash_fwd").as_text()
+    assert len(re.findall(r"%flash_fwd(\.\d+)? = .*custom-call", text)) == 1
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv,
              kernel="flash_fwd")
+
+
+@pytest.mark.parametrize(
+    "arch,Sq,Skv,causal",
+    [
+        ("recurrentgemma-9b", 4096, 4096, True),    # training, window 2048
+        ("recurrentgemma-9b", 32768, 32768, True),  # 32k prefill
+        ("mixtral-8x22b", 4096, 4096, True),        # training, window 4096
+        ("mistral-large-123b", 4096, 4096, True),   # training
+        ("whisper-medium", 448, 1500, False),       # cross-attention
+    ],
+)
+def test_flash_forward_compiles_at_other_widths(one_chip, arch, Sq, Skv,
+                                                causal):
+    """The default tile plan fits the scoped VMEM for the registry's other
+    attention shapes: every query head of the model on one chip, its
+    window, bf16.  Whisper's decoder (448 positions) attends to the
+    encoder's 1500 frames."""
+    from repro.configs import get_config
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_pallas,
+    )
+
+    cfg = get_config(arch)
+    window = cfg.sliding_window or cfg.local_window
+    q = _spec((1, Sq, cfg.num_heads, cfg.head_dim), jnp.bfloat16, one_chip)
+    kv = _spec((1, Skv, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16,
+               one_chip)
+    _compile(functools.partial(flash_attention_pallas, causal=causal,
+                               window=window), q, kv, kv, kernel="flash_fwd")
 
 
 def test_ssd_compiles_at_mamba2_370m_widths(one_chip):
